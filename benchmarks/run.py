@@ -368,6 +368,8 @@ def main() -> None:
               " (open in Perfetto / chrome://tracing) and"
               " metrics_<suite>.json; see docs/observability.md")
         return
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.trace:
         # One switch flips the whole stack: the instrumented seams all go
         # through the repro.obs process-global, and bench modules that

@@ -25,7 +25,7 @@ import time
 
 from .backends import (BACKENDS, ExecutionBackend, InlineBackend,
                        RemoteBackend, ShardedBackend, SubprocessBackend,
-                       execute_trial, get_backend)
+                       deploy_tree, execute_trial, get_backend)
 from .compile import (CompiledExperiment, DriftPlan, MemoryPlan, TrialPlan,
                       TuningPlan, compile_spec, drift_schedule)
 from .report import (Report, Row, TreeProbe, costs_over_benchmark, delta_tp,
@@ -42,7 +42,8 @@ __all__ = [
     "compile_spec", "CompiledExperiment", "TuningPlan", "TrialPlan",
     "DriftPlan", "MemoryPlan", "drift_schedule",
     "BACKENDS", "ExecutionBackend", "InlineBackend", "ShardedBackend",
-    "SubprocessBackend", "RemoteBackend", "get_backend", "execute_trial",
+    "SubprocessBackend", "RemoteBackend", "get_backend", "deploy_tree",
+    "execute_trial",
     "costs_over_benchmark", "delta_tp", "timed", "fmt", "jsonable",
 ]
 

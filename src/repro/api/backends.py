@@ -10,9 +10,11 @@ tuning grid and the engine fleet trial — without changing their semantics:
 * :class:`ShardedBackend` (``"sharded"``) — splits the flattened
   (workload x rho) problem axis across JAX devices with a 1-D
   ``launch.mesh`` mesh + ``NamedSharding`` (each device solves a contiguous
-  slab of the grid's vmap lanes).  On a single-device host it falls back to
-  the inline path, so the same spec runs anywhere — the per-lane solves are
-  independent, which is what makes the sharding semantics-free.
+  slab of the grid's vmap lanes).  On a single-device host it is the
+  inline path, so the same spec runs anywhere.  The per-lane solves are
+  independent, so sharding picks the same designs; each device's program
+  is compiled for its slab, so costs may differ from inline by f32
+  rounding.
 * :class:`SubprocessBackend` (``"subprocess"``) — shards the fleet grid's
   *trees* across worker processes (spawned, jax-free: the engine is pure
   numpy).  Trees sharing a key draw stay on one worker so materialized
@@ -73,6 +75,19 @@ class _PhiLite:
         self.K = np.asarray(K, np.float64)
 
 
+def deploy_tree(plan: TrialPlan, b: TreeBuild):
+    """The empty engine tree one :class:`TreeBuild` deploys (jax-free)."""
+    from repro.lsm import LSMTree
+    tree = LSMTree.from_phi(_PhiLite(b.T, b.mfilt_bits, b.K),
+                            _SysLite(plan.bits_per_entry, plan.sys_N),
+                            expected_entries=plan.n_keys,
+                            entry_bytes=plan.entry_bytes,
+                            policy=b.policy,
+                            policy_params=b.policy_params)
+    tree.obs_label = f"w{b.cell[0]}.rho{b.cell[1]}/{b.policy}"
+    return tree
+
+
 def execute_trial(plan: TrialPlan, trees: Optional[List[TreeBuild]] = None):
     """Build, populate, and run one shard of the fleet grid.
 
@@ -82,10 +97,9 @@ def execute_trial(plan: TrialPlan, trees: Optional[List[TreeBuild]] = None):
     :class:`TreeProbe`.  Pure numpy end-to-end — both the inline backend
     and subprocess workers run exactly this function, so sharding cannot
     change measured I/O."""
-    from repro.lsm import IOStats, LSMTree, draw_keys, populate, run_fleet
+    from repro.lsm import IOStats, draw_keys, populate, run_fleet
 
     builds = plan.trees if trees is None else trees
-    sys_lite = _SysLite(plan.bits_per_entry, plan.sys_N)
     t0 = time.time()
     keys_by_group: Dict[int, np.ndarray] = {}
     dead_by_group: Dict[int, np.ndarray] = {}
@@ -100,13 +114,7 @@ def execute_trial(plan: TrialPlan, trees: Optional[List[TreeBuild]] = None):
                 if plan.delete_fraction > 0:
                     dead_by_group[b.key_group] = \
                         keys[::int(1 / plan.delete_fraction)]
-            tree = LSMTree.from_phi(_PhiLite(b.T, b.mfilt_bits, b.K),
-                                    sys_lite,
-                                    expected_entries=plan.n_keys,
-                                    entry_bytes=plan.entry_bytes,
-                                    policy=b.policy,
-                                    policy_params=b.policy_params)
-            tree.obs_label = f"w{b.cell[0]}.rho{b.cell[1]}/{b.policy}"
+            tree = deploy_tree(plan, b)
             populate(tree, plan.n_keys, key_space=plan.key_space, keys=keys)
             if plan.delete_fraction > 0:
                 for k in dead_by_group[b.key_group]:  # seed tombstones
@@ -157,7 +165,8 @@ class ExecutionBackend:
     is exhausted, ``failed_cells``).  Implementations must be
     *semantics-free*: any backend, on any topology, under any injected
     fault schedule (``faults``, a :class:`repro.faults.FaultPlan`),
-    produces the same tunings and the same measured ``IOStats`` as
+    produces the same tunings (costs to f32 rounding when the grid is
+    split over devices) and the same measured ``IOStats`` as
     :class:`InlineBackend` for every tree it recovers (sharding and
     retrying move work, never change it)."""
 
@@ -238,12 +247,19 @@ class InlineBackend(ExecutionBackend):
         report.walls["fleet_s"] = fleet_s
 
 
+def _shard_devices(x) -> List[int]:
+    """Ids of the devices holding a shard of array ``x``."""
+    return sorted(s.device.id for s in x.addressable_shards)
+
+
 class ShardedBackend(InlineBackend):
     """Device-sharded tuning: the flattened problem axis is placed across
     all JAX devices via ``NamedSharding`` before the single-jit solve, so
-    XLA partitions the vmap lanes device-parallel.  Falls back to the
-    inline path (bit-identical results — the lanes are independent either
-    way) when only one device is visible."""
+    XLA partitions the vmap lanes device-parallel.  With one visible device
+    this is the inline path.  With several, the designs equal inline's and
+    the costs agree to f32 rounding (the partitioned program is compiled
+    for a slab of the lanes); the ``tune.sharded`` event records the
+    devices holding the inputs and outputs."""
 
     name = "sharded"
 
@@ -272,7 +288,15 @@ class ShardedBackend(InlineBackend):
             out = batch.solve_grid(jax.random.PRNGKey(plan.seed), W_d, r_d,
                                    plan.design, plan.sys, plan.n_starts,
                                    plan.steps, plan.lr, robust)
+            placed = ([_shard_devices(x) for x in (W_d, r_d)],
+                      [_shard_devices(x) for x in out])
             out = [np.asarray(x)[:P0] for x in jax.device_get(out)]
+            if obs.enabled():
+                # where the grid really ran (the devices holding each
+                # input's and output's shards), and the padding dropped
+                obs.event("tune.sharded", problems=P0, padded=P0 + pad,
+                          returned=len(out[0]), inputs=placed[0],
+                          outputs=placed[1])
             return batch.build_results(out, plan.design, plan.sys)
 
         out: Dict[Cell, object] = {}

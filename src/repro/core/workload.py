@@ -43,7 +43,10 @@ def worst_case_workload(c: jnp.ndarray, w: jnp.ndarray, rho: float,
     span = jnp.maximum(jnp.max(c) - jnp.min(c), 1e-12)
 
     def tilt(lam):
-        logits = jnp.log(w) + c / jnp.maximum(lam, 1e-12)
+        # Shift by max(c) before dividing: at tiny lam, c / lam alone is so
+        # large that log(w) drops below its f32 ulp, and tied maxima would
+        # split evenly instead of in proportion to w.
+        logits = jnp.log(w) + (c - jnp.max(c)) / jnp.maximum(lam, 1e-12)
         return jax.nn.softmax(logits)
 
     # Degenerate cases: rho <= 0 -> w itself; flat costs -> w itself.

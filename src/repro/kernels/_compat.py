@@ -1,55 +1,21 @@
-"""Pallas TPU API compatibility layer.
+"""Device test and Pallas TPU compiler params shared by the kernel tier.
 
-The kernel tier targets the modern Pallas TPU surface
-(``pltpu.CompilerParams``), but JAX builds in this range ship the same
-object under the older name ``pltpu.TPUCompilerParams`` (and very old
-builds lack the TPU backend entirely).  Kernels import the two symbols
-below instead of reaching into ``pltpu`` directly so that:
-
-* on any JAX with a Pallas TPU backend, ``compiler_params(...)``
-  constructs whichever CompilerParams class exists — kernels construct
-  and run (interpret mode on CPU, Mosaic on TPU);
-* on a JAX without the TPU backend, ``compiler_params(...)`` returns
-  ``None`` (``pl.pallas_call(compiler_params=None)`` is accepted) and
-  ``HAS_MOSAIC`` is False, so callers/tests know only interpret mode is
-  available.
-
-``interpret_default()`` centralises the dispatch rule used by every
-``ops.py``: run compiled only when actually on a TPU backend.
+``interpret_default()`` is the dispatch rule of every ``ops.py``: a
+kernel compiles with Mosaic on a TPU backend and runs in interpret mode
+anywhere else.  ``on_tpu()`` is the one device test behind it.
 """
 
 from __future__ import annotations
 
-from typing import Any, Optional
+from typing import Any
 
 import jax
-
-try:  # pallas TPU backend (present on CPU jaxlib builds too)
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover - very old / trimmed builds
-    pltpu = None
-
-# The class moved names across JAX versions: CompilerParams (new) vs
-# TPUCompilerParams (0.4.x).  Resolve whichever exists.
-_PARAMS_CLS = None
-if pltpu is not None:
-    _PARAMS_CLS = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
-#: True when TPU compiler params can be constructed (Mosaic lowering is
-#: at least expressible; actual compiled execution still needs a TPU).
-HAS_MOSAIC: bool = _PARAMS_CLS is not None
+from jax.experimental.pallas import tpu as pltpu
 
 
-def compiler_params(**kwargs: Any) -> Optional[Any]:
-    """Build a Pallas TPU CompilerParams under whichever name this JAX has.
-
-    Returns None (a valid ``pallas_call`` argument meaning "defaults")
-    when the TPU param class is absent; interpret mode ignores it anyway.
-    """
-    if _PARAMS_CLS is None:
-        return None
-    return _PARAMS_CLS(**kwargs)
+def compiler_params(**kwargs: Any) -> pltpu.CompilerParams:
+    """Pallas TPU compiler params (interpret mode ignores them)."""
+    return pltpu.CompilerParams(**kwargs)
 
 
 def on_tpu() -> bool:

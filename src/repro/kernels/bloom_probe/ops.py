@@ -7,11 +7,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from .._compat import interpret_default
 from .kernel import KEY_TILE, bloom_probe_kernel
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("num_hashes",))
@@ -23,5 +20,5 @@ def bloom_probe(keys: jnp.ndarray, plane: jnp.ndarray,
     pad = (-N) % KEY_TILE
     kp = jnp.pad(keys, (0, pad))
     out = bloom_probe_kernel(kp, plane, num_hashes=num_hashes,
-                             interpret=not _on_tpu())
+                             interpret=interpret_default())
     return out[:N] > 0.5

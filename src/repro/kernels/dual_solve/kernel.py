@@ -24,18 +24,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .._compat import compiler_params, interpret_default
-from .ref import _GR
+from .ref import _GR, first_argmin, pick, scan_offsets
 
 LANE_TILE = 128
-
-
-def _pick(arr: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
-    """arr (k, T), idx (1, T) in [0, k) -> per-column gather via selects
-    (k is tiny and static; avoids an in-kernel gather)."""
-    out = arr[0:1]
-    for j in range(1, arr.shape[0]):
-        out = jnp.where(idx == j, arr[j:j + 1], out)
-    return out
 
 
 def _dual_solve_tile(c_ref, w_ref, rho_ref, llam_ref, val_ref, lnew_ref, *,
@@ -51,14 +42,13 @@ def _dual_solve_tile(c_ref, w_ref, rho_ref, llam_ref, val_ref, lnew_ref, *,
         x = logW + C / lam
         m = jnp.max(x, axis=0, keepdims=True)
         s = m + jnp.log(jnp.sum(jnp.exp(x - m), axis=0, keepdims=True))
-        return rho * lam + lam * s
+        return lam * (rho + s)
 
-    offs = jnp.linspace(-half_width, half_width, n_local)
-    lls = jnp.concatenate([llam + offs[j] for j in range(n_local)], axis=0)
-    vals = jnp.concatenate([g(lls[j:j + 1]) for j in range(n_local)], axis=0)
-    i = jnp.argmin(vals, axis=0)[None, :]
-    llo = _pick(lls, jnp.maximum(i - 1, 0))
-    lhi = _pick(lls, jnp.minimum(i + 1, n_local - 1))
+    lls = [llam + o for o in scan_offsets(half_width, n_local)]
+    vals = [g(ll) for ll in lls]
+    i = first_argmin(vals)
+    llo = pick(lls, jnp.maximum(i - 1, 0))
+    lhi = pick(lls, jnp.minimum(i + 1, n_local - 1))
 
     a0 = lhi - _GR * (lhi - llo)
     b0 = llo + _GR * (lhi - llo)

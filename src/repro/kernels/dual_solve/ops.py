@@ -31,7 +31,8 @@ import jax.numpy as jnp
 
 from repro import obs
 
-from .ref import _GR, dual_solve_warm_ref, g_of_llam
+from .ref import (_GR, dual_solve_warm_ref, first_argmin, g_of_llam, pick,
+                  scan_offsets)
 
 
 def dual_solve_warm_fused(c: jnp.ndarray, w: jnp.ndarray, rho, llam,
@@ -48,12 +49,11 @@ def dual_solve_warm_fused(c: jnp.ndarray, w: jnp.ndarray, rho, llam,
     logw = jnp.log(w)
     llam = jax.lax.stop_gradient(llam)
 
-    offs = jnp.linspace(-half_width, half_width, n_local)
-    lls = llam + offs
-    vals = jax.vmap(lambda ll: g_of_llam(c, logw, rho, ll))(lls)
-    i = jnp.argmin(vals)
-    llo = lls[jnp.maximum(i - 1, 0)]
-    lhi = lls[jnp.minimum(i + 1, n_local - 1)]
+    lls = [llam + o for o in scan_offsets(half_width, n_local)]
+    vals = [g_of_llam(c, logw, rho, ll) for ll in lls]
+    i = first_argmin(vals)
+    llo = pick(lls, jnp.maximum(i - 1, 0))
+    lhi = pick(lls, jnp.minimum(i + 1, n_local - 1))
 
     a0 = lhi - _GR * (lhi - llo)
     b0 = llo + _GR * (lhi - llo)

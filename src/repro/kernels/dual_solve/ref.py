@@ -19,10 +19,43 @@ the perf baseline the fused paths are gated against.
 
 from __future__ import annotations
 
+from typing import Sequence, Tuple
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 _GR = 0.6180339887498949  # golden ratio conjugate
+
+
+def scan_offsets(half_width: float, n_local: int) -> Tuple[float, ...]:
+    """The local scan's log-lam offsets as static Python floats.
+
+    The fused tiers add these to the carried ``log lam*`` as constants:
+    an in-kernel ``jnp.linspace`` lowers to an f32 ``iota``, which Mosaic
+    refuses on TPU."""
+    return tuple(np.linspace(-half_width, half_width, n_local).tolist())
+
+
+def first_argmin(vals: Sequence[jnp.ndarray]) -> jnp.ndarray:
+    """Index of the first minimum across a short static list of
+    same-shape arrays, as int32 selects (no ``argmin`` reduction, which
+    the Pallas TPU lowering does not take)."""
+    best = vals[0]
+    idx = jnp.zeros(jnp.shape(best), jnp.int32)
+    for j in range(1, len(vals)):
+        better = vals[j] < best
+        idx = jnp.where(better, j, idx)
+        best = jnp.where(better, vals[j], best)
+    return idx
+
+
+def pick(vals: Sequence[jnp.ndarray], idx: jnp.ndarray) -> jnp.ndarray:
+    """``vals[idx]`` elementwise over a short static list, via selects."""
+    out = vals[0]
+    for j in range(1, len(vals)):
+        out = jnp.where(idx == j, vals[j], out)
+    return out
 
 
 def lse(x: jnp.ndarray) -> jnp.ndarray:
@@ -37,7 +70,9 @@ def g_of_llam(c: jnp.ndarray, logw: jnp.ndarray, rho: jnp.ndarray,
               llam: jnp.ndarray) -> jnp.ndarray:
     """g(exp(llam)) for one lane: c, logw (n,); rho, llam scalars."""
     lam = jnp.maximum(jnp.exp(llam), 1e-12)
-    return rho * lam + lam * lse(logw + c / lam)
+    # lam * (rho + s), not rho * lam + lam * s: no multiply feeds an add,
+    # so no fused multiply-add can round the two tiers apart.
+    return lam * (rho + lse(logw + c / lam))
 
 
 def dual_solve_warm_ref(c: jnp.ndarray, w: jnp.ndarray, rho, llam,

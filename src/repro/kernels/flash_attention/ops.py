@@ -13,11 +13,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from .._compat import interpret_default
 from .kernel import flash_attention_kernel
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_kv"))
@@ -40,5 +37,5 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     vf = v.transpose(0, 2, 1, 3).reshape(B * H, v.shape[1], d)
     out = flash_attention_kernel(qf, kf, vf, causal=causal, window=window,
                                  block_q=block_q, block_kv=block_kv,
-                                 interpret=not _on_tpu())
+                                 interpret=interpret_default())
     return out.reshape(B, H, S, d).transpose(0, 2, 1, 3)

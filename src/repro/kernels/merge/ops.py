@@ -8,7 +8,7 @@ anyway).  Newest-wins is associative, so the fold is bit-identical to
 the legacy global argsort-merge — asserted by the store-level golden
 tests.
 
-Runs under ``jax.experimental.enable_x64`` (uint64 keys, int64 encoded
+Runs under ``jax.enable_x64(True)`` (uint64 keys, int64 encoded
 values — the engine's exact dtypes).
 """
 
@@ -44,7 +44,7 @@ def merge_runs_arrays(keys_list: Sequence[np.ndarray],
 
     acc_k = np.asarray(keys_list[0], np.uint64)
     acc_v = np.asarray(vals_list[0], np.int64)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         for k, v in zip(keys_list[1:], vals_list[1:]):
             if len(k) == 0:
                 continue

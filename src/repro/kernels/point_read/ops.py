@@ -40,7 +40,7 @@ def point_read_level_arrays(sub_keys: np.ndarray, arena_keys: np.ndarray,
         # All runs empty: every key stays live through every run, all
         # Bloom words are zero, so only probes accrue (R per key).
         return (np.zeros(B, bool), np.zeros(B, np.int64), R * B, 0, 0)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         keys_j = jnp.asarray(sub_keys, jnp.uint64)
         ak = jnp.asarray(arena_keys, jnp.uint64)
         av = jnp.asarray(arena_vals, jnp.int64)

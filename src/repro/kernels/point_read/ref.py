@@ -8,7 +8,7 @@ mirror it op for op.  Counters come back *per key* (their sums are the
 engine's integers; the decomposition is what the bit-equivalence tests
 compare).
 
-Requires 64-bit mode (``jax.experimental.enable_x64``): the Bloom hash
+Requires 64-bit mode (``jax.enable_x64(True)``): the Bloom hash
 is the engine's exact splitmix64 over uint64 keys.  ``ops.py`` manages
 the x64 scope; on TPU hardware uint64 would need limb emulation — this
 tier is exercised in interpret mode until then (see docs/kernels.md).

@@ -7,11 +7,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from .._compat import interpret_default
 from .kernel import rwkv6_kernel
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("chunk",))
@@ -23,6 +20,6 @@ def rwkv6_chunked(r, k, v, logw, u, chunk: int = 32):
     u_flat = jnp.tile(u, (B, 1))
     y, state = rwkv6_kernel(to_flat(r), to_flat(k), to_flat(v),
                             to_flat(logw), u_flat, chunk=chunk,
-                            interpret=not _on_tpu())
+                            interpret=interpret_default())
     y = y.reshape(B, H, S, n).transpose(0, 2, 1, 3)
     return y, state.reshape(B, H, n, n)

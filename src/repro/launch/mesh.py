@@ -31,8 +31,12 @@ def make_problem_mesh():
     flatten the (workload x rho) cross product onto one problem axis, and a
     ``NamedSharding(mesh, P("problem"))`` on the inputs lets XLA partition
     the independent vmap lanes device-parallel (see
-    ``repro.api.backends.ShardedBackend``)."""
-    return jax.make_mesh((len(jax.devices()),), ("problem",))
+    ``repro.api.backends.ShardedBackend``).  The axis is ``Auto``: the
+    compiler propagates the problem sharding to the values the grid derives
+    inside (the start inits), which an ``Explicit`` axis, ``make_mesh``'s
+    default, refuses at the vmap."""
+    return jax.make_mesh((len(jax.devices()),), ("problem",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def make_host_mesh(model: int = 1):

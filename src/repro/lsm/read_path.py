@@ -14,7 +14,7 @@ Three implementations behind :func:`point_read_level`:
   execution backend's workers import the engine without jax, so this
   module must stay jax-free unless an opt-in mode is selected.
 * ``jnp`` — the dense jax reference (``repro.kernels.point_read.ref``),
-  lazily imported; exact splitmix64 under ``jax.experimental.enable_x64``.
+  lazily imported; exact splitmix64 under ``jax.enable_x64(True)``.
 * ``jnp_limb`` — the same reference with the Bloom hash on uint32 limbs
   (``repro.kernels.point_read.limb``): the TPU-portable arithmetic tier,
   bit-identical to the native uint64 hash.

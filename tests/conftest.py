@@ -14,12 +14,9 @@
    the matrix's real-hypothesis leg is where they count.
 
 2. Kernel tests (``test_kernels.py``, ``test_engine_kernels.py``) run on
-   every container: kernels resolve the Pallas TPU CompilerParams class
-   through ``repro.kernels._compat`` (``CompilerParams`` vs the older
-   ``TPUCompilerParams`` spelling, or None when the TPU backend is
-   absent), and the tests pin ``interpret=True`` so no Mosaic lowering
-   is required.  The compiled leg is auto-selected by the ``ops.py``
-   dispatch wrappers when the default backend is a real TPU.
+   the CPU: the tests pin ``interpret=True`` so no Mosaic lowering is
+   required.  The compiled leg is selected by the ``ops.py`` dispatch
+   wrappers when the default backend is a TPU.
 """
 
 import importlib.util
@@ -93,9 +90,6 @@ if _HYPOTHESIS_STUBBED:
     sys.modules["hypothesis.strategies"] = _strategies
 
 # --- 2. pytest hooks ---------------------------------------------------------
-# (test_kernels.py gates itself on the Pallas TPU API surface with a
-# module-level pytest.skip, so its absence shows up as a skip with a reason
-# rather than a silent collect_ignore.)
 
 
 def pytest_configure(config):

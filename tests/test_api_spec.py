@@ -142,6 +142,86 @@ def test_subprocess_backend_matches_inline():
     assert sub.walls["trial_workers"] == 2
 
 
+_SHARDED_SCRIPT = """
+import dataclasses, json, jax
+from repro import obs
+from repro.api import run_experiment
+import test_api_spec as t
+spec = t._spec()
+with obs.scoped():
+    sharded = run_experiment(dataclasses.replace(spec, backend="sharded"))
+    events = [e["attrs"] for e in obs.events_snapshot()
+              if e["name"] == "tune.sharded"]
+inline = run_experiment(spec)
+cells = [[repr(c), float(sharded.tuning(c).phi.T),
+          float(inline.tuning(c).phi.T), sharded.tuning(c).cost,
+          inline.tuning(c).cost] for c in inline.cells]
+print(json.dumps({"devices": len(jax.devices()), "events": events,
+                  "cells": cells}))
+"""
+
+
+def test_sharded_backend_spans_four_devices():
+    """On a host with four devices the grid's problem axis really is split
+    (inputs and outputs on all four, padding dropped), and every cell keeps
+    the inline design with its cost equal to f32 rounding: each device's
+    program is compiled for a quarter of the lanes."""
+    import json
+    import os
+    import subprocess
+    import sys
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.pathsep.join(p for p in sys.path if p))
+    out = subprocess.run([sys.executable, "-c", _SHARDED_SCRIPT], env=env,
+                         capture_output=True, text=True, timeout=600,
+                         cwd=os.path.dirname(__file__))
+    assert out.returncode == 0, out.stderr[-2000:]
+    got = json.loads(out.stdout.strip().splitlines()[-1])
+    assert got["devices"] == 4
+    assert [(e["problems"], e["padded"], e["returned"])
+            for e in got["events"]] == [(2, 4, 2), (4, 4, 4)]
+    for e in got["events"]:
+        assert e["inputs"] == [[0, 1, 2, 3]] * 2
+        assert e["outputs"] == [[0, 1, 2, 3]] * len(e["outputs"])
+    for cell, t_sh, t_in, c_sh, c_in in got["cells"]:
+        assert t_sh == t_in, cell
+        assert c_sh == pytest.approx(c_in, rel=1e-5), cell
+
+
+_WORKER_SCRIPT = """
+import sys
+from repro.api.backends import _worker_main
+_worker_main()
+assert "jax" not in sys.modules, "the worker imported jax"
+"""
+
+
+def test_subprocess_worker_never_imports_jax():
+    """A fleet worker runs its shard without importing jax, so it cannot
+    take or wait for the accelerator its parent holds."""
+    import os
+    import pickle
+    import subprocess
+    import sys
+    from repro.api import compile_spec
+    spec = _spec(
+        workload=WorkloadSpec(indices=WIDX[:1], rhos=(1.0,), nominal=False),
+        trial=TrialSpec(n_keys=2000, n_queries=200, sessions=SESSIONS,
+                        key_space=2 ** 22))
+    report = run_experiment(spec)
+    plan = compile_spec(spec).build_trial(report)
+    env = dict(os.environ,
+               PYTHONPATH=os.pathsep.join(p for p in sys.path if p))
+    out = subprocess.run([sys.executable, "-c", _WORKER_SCRIPT],
+                         input=pickle.dumps((plan, plan.trees, None)),
+                         env=env, capture_output=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    results, _, _, _ = pickle.loads(out.stdout)
+    assert [r.io for r in results[0]] == \
+        [r.io for r in report.fleet[((0, 1.0), "klsm")]]
+
+
 # ---------------------------------------------------------------------------
 # Joint policy-arm selection + report surface
 # ---------------------------------------------------------------------------

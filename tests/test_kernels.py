@@ -1,13 +1,11 @@
 """Per-kernel validation: shape/dtype sweeps in interpret mode against the
 pure-jnp oracles (+ hypothesis property tests).
 
-Kernels construct their CompilerParams through ``repro.kernels._compat``
-(which resolves ``pltpu.CompilerParams`` vs the older
-``pltpu.TPUCompilerParams`` spelling, or returns None on builds without
-the TPU backend), so this module runs everywhere: the interpret leg
-(``interpret=True``, exercised below) works on any backend, and the
-compiled leg is auto-selected by each ``ops.py`` wrapper when the
-default backend is an actual TPU.
+The interpret leg (``interpret=True``, exercised below) runs on any
+backend; each ``ops.py`` wrapper selects the compiled leg when the
+default backend is a TPU (``repro.kernels._compat.interpret_default``).
+Which kernels Mosaic accepts for v5e is checked by
+``tests/test_tpu_compile.py``.
 """
 
 import pytest

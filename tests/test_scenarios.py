@@ -397,7 +397,7 @@ def test_overlap_scoring_prefers_empty_target_span():
 def test_limb_splitmix64_bit_identity():
     import jax
     from repro.lsm.bloom import splitmix64
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         import jax.numpy as jnp
         from repro.kernels.point_read.limb import (from_limbs, mod_limbs,
                                                    split64_jnp,

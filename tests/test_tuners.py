@@ -55,6 +55,18 @@ def test_worst_case_in_uncertainty_region(c, w, rho):
     assert float(jnp.dot(w_hat, c)) >= float(jnp.dot(w, c)) - 1e-5
 
 
+def test_worst_case_ties_split_in_proportion_to_w():
+    """Tied maximal costs, ball wide enough for the point-mass limit: the
+    worst case puts all mass on the ties in proportion to w (KL = log 2
+    here), not evenly (KL 0.752 > rho)."""
+    c = jnp.asarray([1.0, 1.0, 0.5, 0.5], jnp.float32)
+    w = jnp.asarray([1.0, 0.5, 0.5, 1.0], jnp.float32) / 3.0
+    w_hat = worst_case_workload(c, w, 0.75)
+    np.testing.assert_allclose(np.asarray(w_hat), [2 / 3, 1 / 3, 0, 0],
+                               atol=1e-6)
+    assert float(kl_divergence(w_hat, w)) <= 0.75
+
+
 @settings(max_examples=30, deadline=None)
 @given(c=cost_strat, w=w_strat, rho=rho_strat)
 def test_eta_elimination_exact(c, w, rho):
