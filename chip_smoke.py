@@ -12,8 +12,10 @@ exit and no result line:
 2. tune    -- the Figure 6 grid (``bench_robust_vs_nominal.SPEC``: 15
    expected workloads x 5 rhos plus nominal, paper-default
    ``LSMSystem``) through ``run_experiment`` on the TPU, then the same
-   grid on the host CPU of this process.  Every cell's cost must agree
-   within ``COST_RTOL``.
+   grid on the host CPU of this process.  Both devices must score the
+   same tunings alike (``EVAL_RTOL``), and each device's tuned costs
+   must agree within the search's own seed-to-seed spread
+   (``SEARCH_RTOL`` per cell, ``SEARCH_MEAN_RTOL`` on average).
 3. serve   -- the Table 5 spec (``bench_system_eval``: 5 workloads,
    nominal + robust at rho = 1, four drifted sessions) deployed at
    ``N_KEYS`` keys with ``QUERIES`` operations per session.  Every served
@@ -64,14 +66,24 @@ LEGACY_QUERIES = 2_000
 WORKER_KEYS = 100_000         # the subprocess-vs-inline trial
 WORKER_QUERIES = 10_000
 
-# TPU and CPU both run the grid in f32, but their exp/log and reduction
-# orders differ by a few ulp, and a dot at the TPU's default precision may
-# round its inputs to bf16 (2^-9 relative per product).  Adam amplifies
-# such noise into the continuous filter-memory share, and a cell whose two
-# best integral designs tie within noise may pick either.  A cell's cost
-# is a robust objective; 1e-2 covers all of the above with margin, while a
-# wrong program moves it by tens of percent (robust vs nominal gaps).
-COST_RTOL = 1e-2
+# The tune phase checks the device's arithmetic and its search apart.
+#
+# Arithmetic: the exact objective (cost model + cold-grid KL dual) of one
+# integral tuning is a pure f32 function of about a hundred operations.
+# TPU and CPU differ only in the last bits of exp/log and in reduction
+# order (a few ulp each, 2^-23 = 1.2e-7), and the golden-section value
+# error is second order in the bracket, so both devices score the same
+# tuning alike to EVAL_RTOL.  A dot rounded to bf16 (2^-9) would not pass.
+EVAL_RTOL = 1e-4
+# Search: the tuner rounds a continuous optimum to integers (ceil T,
+# round K), so ulp-level noise in 250 Adam steps can land a cell on a
+# neighbouring integral design, as another seed does.  On the CPU alone,
+# seeds 1-23 against seed 0 move single Figure 6 cells by up to 8.0% and
+# the mean over the 90 cells by at most 1.7e-3 (|rel|).  The TPU's run is
+# one more such draw: its cells must agree within SEARCH_RTOL and their
+# mean within SEARCH_MEAN_RTOL.
+SEARCH_RTOL = 0.1
+SEARCH_MEAN_RTOL = 5e-3
 
 # The sharded grid is the same computation, but compiled for a quarter of
 # the lanes per device, so its reductions may round differently: designs
@@ -117,28 +129,65 @@ def _discrete(t):
     return (t.design, float(t.phi.T), tuple(np.asarray(t.phi.K).tolist()))
 
 
+def score_tunings(spec, report):
+    """Exact objective of every cell's tuning in ``report`` (cost model +
+    cold-grid KL dual, as the tuner scores its winner), as one jitted
+    program on JAX's default device.  Returns a float64 array by cell."""
+    import jax
+    from repro.api import compile_spec
+    from repro.core import Phi, cost_vector, robust_cost
+    sys_ = report.sys
+    W = compile_spec(spec).W
+    w = np.stack([np.asarray(W[i], np.float32) for i, _ in report.cells])
+    rho = np.array([r or 0.0 for _, r in report.cells], np.float32)
+    phis = [report.tuning(c).phi for c in report.cells]
+    phi = Phi(T=np.array([p.T for p in phis], np.float32),
+              mfilt_bits=np.array([p.mfilt_bits for p in phis], np.float32),
+              K=np.stack([np.asarray(p.K, np.float32) for p in phis]))
+    score = jax.jit(jax.vmap(
+        lambda p, w, r: robust_cost(cost_vector(p, sys_), w, r)))
+    return np.asarray(score(phi, w, rho), np.float64)
+
+
 def phase_tune(spec) -> None:
     import jax
     from repro.api import run_experiment
+    cpu_dev = jax.devices("cpu")[0]
     with Timer() as tt:
         tpu = run_experiment(spec)
-    with Timer() as tc, jax.default_device(jax.devices("cpu")[0]):
+    with Timer() as tc, jax.default_device(cpu_dev):
         cpu = run_experiment(spec)
-    worst, differ = 0.0, 0
-    for cell in tpu.cells:
-        a, b = tpu.tuning(cell), cpu.tuning(cell)
-        if not (np.isfinite(a.cost) and np.isfinite(b.cost)):
-            raise AssertionError(f"tune: cell {cell} cost is not finite "
-                                 f"(tpu {a.cost}, cpu {b.cost})")
-        worst = max(worst, abs(a.cost - b.cost) / abs(b.cost))
-        differ += _discrete(a) != _discrete(b)
     say(f"tune: {len(tpu.cells)} cells; tpu {tt}; cpu {tc}")
-    say(f"tune: max relative cost difference tpu vs cpu {worst:.3e} "
-        f"(tolerance {COST_RTOL:g}); cells with a different design "
-        f"{differ}/{len(tpu.cells)}")
-    if worst > COST_RTOL:
-        raise AssertionError(f"tune: tpu and cpu costs differ by {worst:.3e}"
-                             f" > {COST_RTOL:g}")
+    # arithmetic: both devices score both devices' tunings
+    evals = []
+    for report in (tpu, cpu):
+        on_tpu = score_tunings(spec, report)
+        with jax.default_device(cpu_dev):
+            on_cpu = score_tunings(spec, report)
+        if not (np.isfinite(on_tpu).all() and np.isfinite(on_cpu).all()):
+            raise AssertionError("tune: an exact objective is not finite")
+        evals.append(np.abs(on_tpu - on_cpu) / np.abs(on_cpu))
+    ev = np.concatenate(evals)
+    say(f"tune: exact objective of the same {len(ev)} tunings, tpu vs cpu: "
+        f"max relative difference {ev.max():.3e}, bit-equal in "
+        f"{int((ev == 0).sum())} (tolerance {EVAL_RTOL:g})")
+    # search: each device's own winner per cell
+    a = np.array([tpu.tuning(c).cost for c in tpu.cells], np.float64)
+    b = np.array([cpu.tuning(c).cost for c in tpu.cells], np.float64)
+    rel = np.abs(a - b) / np.abs(b)
+    differ = sum(_discrete(tpu.tuning(c)) != _discrete(cpu.tuning(c))
+                 for c in tpu.cells)
+    say(f"tune: tuned cost tpu vs cpu: max relative difference "
+        f"{rel.max():.3e} (tolerance {SEARCH_RTOL:g}), mean {rel.mean():.3e} "
+        f"(tolerance {SEARCH_MEAN_RTOL:g}); cells with a different design "
+        f"{differ}/{len(rel)}, tpu lower in {int((a < b).sum())}, higher in "
+        f"{int((a > b).sum())}")
+    if ev.max() > EVAL_RTOL:
+        raise AssertionError(f"tune: tpu and cpu score the same tuning "
+                             f"{ev.max():.3e} apart > {EVAL_RTOL:g}")
+    if rel.max() > SEARCH_RTOL or rel.mean() > SEARCH_MEAN_RTOL:
+        raise AssertionError(f"tune: tuned costs differ by {rel.max():.3e} "
+                             f"(mean {rel.mean():.3e})")
 
 
 # ---------------------------------------------------------------------------
