@@ -32,6 +32,7 @@ from .report import (Report, Row, TreeProbe, costs_over_benchmark, delta_tp,
                      fmt, jsonable, timed)
 from .spec import (DesignSpec, DriftSpec, ExperimentSpec, MemorySpec,
                    TrialSpec, WorkloadSpec)
+from repro import obs
 from repro.faults import FaultPlan, FaultSpec
 
 __all__ = [
@@ -65,15 +66,16 @@ def run_experiment(spec: ExperimentSpec, backend=None) -> Report:
         backend = get_backend(spec.backend, spec.backend_params)
     faults = FaultPlan.from_specs(spec.faults) if spec.faults else None
 
-    t0 = time.time()
+    t0 = time.perf_counter()
     solved = {design: backend.solve(plan)
               for design, plan in cx.tuning_plans().items()}
-    tuning_s = time.time() - t0
+    tuning_s = time.perf_counter() - t0
 
-    t0 = time.time()
-    report = cx.select_arms(solved)
+    t0 = time.perf_counter()
+    with obs.span("tune.select", cells=len(cx.cells)):
+        report = cx.select_arms(solved)
     report.walls["tuning_s"] = tuning_s
-    report.walls["select_s"] = time.time() - t0
+    report.walls["select_s"] = time.perf_counter() - t0
 
     trial = cx.build_trial(report)
     if trial is not None:

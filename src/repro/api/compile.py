@@ -29,6 +29,8 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
+
 from .report import Cell, Report
 from .spec import ExperimentSpec, Pairs
 
@@ -198,12 +200,12 @@ def _arm_scorer(sys, policy: str, params: Pairs):
         from repro.core.robust import robust_cost
 
         @jax.jit
-        def fn(phi, w, rho):
+        def _score_arm(phi, w, rho):
             eff = policy_effective_phi(phi, sys, policy, params)
             c = cost_vector(eff, sys)
             return c, robust_cost(c, w, rho)
 
-        _ARM_SCORERS[key] = fn
+        fn = _ARM_SCORERS[key] = _score_arm
     return fn
 
 
@@ -288,7 +290,8 @@ class CompiledExperiment:
                             design=self.primary_design, solver="fixed")
 
     def select_arms(self, solved: Dict[object, Dict[Cell, object]]) -> Report:
-        """Joint policy-arm selection + the model-side report skeleton.
+        """Joint policy-arm selection + the model-side report skeleton
+        (each scorer call, up to its sync, under a ``tune.score`` span).
 
         ``solved`` maps design -> cell -> TuningResult (the backends'
         output).  Each arm is scored by the exact objective of its
@@ -317,10 +320,11 @@ class CompiledExperiment:
                 r = fixed if fixed is not None \
                     else solved[(self.arm_design[pol],
                                  spec.design.n_starts)][cell]
-                c, cost = scorers[pol](r.phi, w,
-                                       np.float32(rho or 0.0))
+                with obs.span("tune.score", policy=pol):
+                    c, cost = scorers[pol](r.phi, w,
+                                           np.float32(rho or 0.0))
+                    costs[pol] = float(cost)
                 arms[pol] = r
-                costs[pol] = float(cost)
                 models[pol] = np.asarray(c, np.float64)
             best = min(costs, key=lambda p: (costs[p],
                                              spec.design.policies.index(p)))
@@ -336,7 +340,8 @@ class CompiledExperiment:
         # space arm equals a separate spec with that primary space exactly)
         design_tunings: Dict[str, Dict[Cell, object]] = {}
         design_bench_costs: Dict[str, Dict[Cell, np.ndarray]] = {}
-        primary_scorer = scorers[spec.design.policies[0]]
+        primary = spec.design.policies[0]
+        primary_scorer = scorers[primary]
         for name, key in self.space_arms:
             per_cell = dict(solved[key])
             design_tunings[name] = per_cell
@@ -345,10 +350,13 @@ class CompiledExperiment:
                 costs_d: Dict[Cell, np.ndarray] = {}
                 for cell in self.cells:
                     i, rho = cell
-                    c, _ = primary_scorer(per_cell[cell].phi,
-                                          np.asarray(self.W[i], np.float32),
-                                          np.float32(rho or 0.0))
-                    costs_d[cell] = B @ np.asarray(c, np.float64)
+                    with obs.span("tune.score", policy=primary):
+                        c, _ = primary_scorer(
+                            per_cell[cell].phi,
+                            np.asarray(self.W[i], np.float32),
+                            np.float32(rho or 0.0))
+                        c = np.asarray(c, np.float64)
+                    costs_d[cell] = B @ c
                 design_bench_costs[name] = costs_d
         return Report(spec=spec, sys=self.sys, cells=list(self.cells),
                       tunings=tunings, arm_costs=arm_costs, chosen=chosen,

@@ -79,9 +79,20 @@ def retune_storm(workloads, rhos, sys, seed: int = 0, design=None,
         raise ValueError(f"{len(W)} workloads for {len(R)} rhos")
     obs.count("tuner.storms")
     obs.count("tuner.storm_requests", len(W))
-    with obs.span("tuner.storm", requests=len(W), pad_pow2=bool(pad_pow2)):
+    n_nom = int((R <= 0).sum())
+    n_rob_rhos = len(set(R[R > 0].tolist()))
+    problems = _rows(n_nom, pad_pow2) \
+        + _rows(len(W) - n_nom, pad_pow2) * n_rob_rhos
+    with obs.span("tuner.storm", requests=len(W), pad_pow2=bool(pad_pow2),
+                  problems=problems, tunings=len(W)):
         return _retune_storm(W, R, sys, seed, design, n_starts, steps, lr,
                              pad_pow2)
+
+
+def _rows(n: int, pad_pow2: bool) -> int:
+    """The rows a batch of ``n`` workloads takes (the next power of two
+    with ``pad_pow2``)."""
+    return 1 << (n - 1).bit_length() if pad_pow2 and n >= 2 else n
 
 
 def _retune_storm(W, R, sys, seed, design, n_starts, steps, lr,
@@ -92,9 +103,9 @@ def _retune_storm(W, R, sys, seed, design, n_starts, steps, lr,
         kw["design"] = design
 
     def padded(M: np.ndarray) -> np.ndarray:
-        if not pad_pow2 or len(M) < 2:
+        P = _rows(len(M), pad_pow2)
+        if P == len(M):
             return M
-        P = 1 << (len(M) - 1).bit_length()
         return np.concatenate([M, np.repeat(M[-1:], P - len(M), axis=0)])
 
     out: list = [None] * len(W)

@@ -12,6 +12,8 @@
 - robust_sharding: beyond-paper — same dual applied to mesh/layout selection
 """
 
+from repro.obs import jax_bridge as _jax_bridge
+
 from .batch import (build_results, solve_grid, tune_nominal_many,
                     tune_robust_many)
 from .designs import (ENGINE_POLICIES, LAZY_LEVELING_FILL, DesignSpace,
@@ -44,3 +46,6 @@ __all__ = [
     "EXPECTED_WORKLOADS", "WORKLOAD_CATEGORY", "sample_benchmark",
     "zippydb_like",
 ]
+
+# every obs span is a profiler span too, and compiles are tallied
+_jax_bridge.install()

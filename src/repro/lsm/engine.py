@@ -316,38 +316,41 @@ class LSMTree:
         if not self.buffer:
             return
         obs.count("engine.flush")
-        keys = np.fromiter(self.buffer.keys(), np.uint64, len(self.buffer))
-        vals = np.fromiter(self.buffer.values(), np.int64, len(self.buffer))
-        order = np.argsort(keys)
-        self.flush_seq += 1
-        tomb_seq = self.flush_seq if bool((vals == TOMB).any()) else -1
-        run = RunData.build(keys[order], vals[order], self._bits_per_key(1),
-                            flushes=1, tomb_seq=tomb_seq)
-        self.stats.comp_pages_written += pages_of(
-            len(run), self.cfg.entries_per_page)   # sequential flush
-        self.buffer.clear()
-        self._push_run(1, run)
-        self._maintain()
-        # Compaction-time intern reclamation: the buffer is empty here, so
-        # every live interned slot is visible in the level arenas.
-        if len(self.store.codec.objects) >= self._intern_sweep_at:
-            self.store.reclaim_interned()
-            self._intern_sweep_at = max(64, 2 * len(self.store.codec.objects))
+        with obs.span("engine.flush", entries=len(self.buffer)):
+            n = len(self.buffer)
+            keys = np.fromiter(self.buffer.keys(), np.uint64, n)
+            vals = np.fromiter(self.buffer.values(), np.int64, n)
+            order = np.argsort(keys)
+            self.flush_seq += 1
+            tomb_seq = self.flush_seq if bool((vals == TOMB).any()) else -1
+            run = RunData.build(keys[order], vals[order],
+                                self._bits_per_key(1), flushes=1,
+                                tomb_seq=tomb_seq)
+            self.stats.comp_pages_written += pages_of(
+                len(run), self.cfg.entries_per_page)   # sequential flush
+            self.buffer.clear()
+            self._push_run(1, run)
+            self._maintain()
+            # Compaction-time intern reclamation: the buffer is empty here,
+            # so every live interned slot is visible in the level arenas.
+            if len(self.store.codec.objects) >= self._intern_sweep_at:
+                self.store.reclaim_interned()
+                self._intern_sweep_at = max(
+                    64, 2 * len(self.store.codec.objects))
 
     def _execute_plan(self, plan, run, bpk):
-        """``store.execute`` with per-plan telemetry counters attached:
-        plan kinds, compactions per policy, and compaction page deltas."""
-        if not obs.enabled():
-            return self.store.execute(plan, run, self.stats, bpk)
-        s = self.stats
-        read0, written0 = s.comp_pages_read, s.comp_pages_written
-        out = self.store.execute(plan, run, s, bpk)
-        obs.count("engine.plan." + plan.kind)
-        obs.count("engine.compaction." + self.cfg.policy)
-        obs.count("engine.comp_pages_read", s.comp_pages_read - read0)
-        obs.count("engine.comp_pages_written",
-                  s.comp_pages_written - written0)
-        return out
+        """``store.execute`` under the ``engine.compact`` span, with
+        telemetry attached: plan kinds and compactions per policy as
+        counters, compaction page deltas on the span."""
+        with obs.span("engine.compact", kind=plan.kind) as sp:
+            s = self.stats
+            read0, written0 = s.comp_pages_read, s.comp_pages_written
+            out = self.store.execute(plan, run, s, bpk)
+            obs.count("engine.plan." + plan.kind)
+            obs.count("engine.compaction." + self.cfg.policy)
+            sp.set(comp_pages_read=s.comp_pages_read - read0,
+                   comp_pages_written=s.comp_pages_written - written0)
+            return out
 
     def _push_run(self, level: int, run: RunData) -> None:
         """Plan-execute-replan until the incoming run finds a home."""
@@ -508,26 +511,23 @@ class LSMTree:
                              use_buffer: bool = True
                              ) -> Tuple[np.ndarray, np.ndarray]:
         """The accounting core of :meth:`point_query_batch`, without
-        materializing a Python result list (the fleet executor's path)."""
-        s = self.stats
-        before = ((s.bloom_probes, s.bloom_false_positives, s.random_reads)
-                  if obs.enabled() else None)
-        found, enc = self._lookup_batch(keys_arr, resolved=resolved,
-                                        found=found, enc=enc,
-                                        use_buffer=use_buffer)
-        nz1 = int(found.sum())
-        self.stats.queries["z1"] += nz1
-        self.stats.queries["z0"] += len(keys_arr) - nz1
-        if before is not None:
-            obs.count("engine.read.batches")
-            obs.count("engine.read.keys", len(keys_arr))
-            obs.count("engine.bloom.probes", s.bloom_probes - before[0])
-            obs.count("engine.bloom.false_positives",
-                      s.bloom_false_positives - before[1])
-            obs.count("engine.read.random_reads",
-                      s.random_reads - before[2])
-        self._maintain()     # read-triggered policies fire at batch ends
-        return found, enc
+        materializing a Python result list (the fleet executor's path),
+        under the ``engine.read`` span (with its ``IOStats`` deltas)."""
+        with obs.span("engine.read", keys=len(keys_arr)) as sp:
+            s = self.stats
+            before = (s.bloom_probes, s.bloom_false_positives,
+                      s.random_reads)
+            found, enc = self._lookup_batch(keys_arr, resolved=resolved,
+                                            found=found, enc=enc,
+                                            use_buffer=use_buffer)
+            nz1 = int(found.sum())
+            s.queries["z1"] += nz1
+            s.queries["z0"] += len(keys_arr) - nz1
+            sp.set(bloom_probes=s.bloom_probes - before[0],
+                   bloom_false_positives=s.bloom_false_positives - before[1],
+                   random_reads=s.random_reads - before[2])
+            self._maintain()     # read-triggered policies fire at batch ends
+            return found, enc
 
     def range_query(self, lo: int, hi: int) -> List[Tuple[int, Any]]:
         return self.range_query_batch([lo], [hi], return_results=True)[0]

@@ -105,8 +105,9 @@ def populate(tree: LSMTree, n: int, seed: int = 7,
     if keys is None:
         keys = draw_keys(n, seed=seed, key_space=key_space)
     values = (keys % np.uint64(997)).astype(np.int64)
-    tree.put_batch(keys, values)
-    tree.flush()
+    with obs.span("lsm.populate", records=int(n)):
+        tree.put_batch(keys, values)
+        tree.flush()
     # Population writes/compactions are setup cost, not workload cost.
     tree.stats = IOStats()
     return keys

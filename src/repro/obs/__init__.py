@@ -5,20 +5,21 @@ Stdlib-only (importable from jax-free subprocess workers, like
 process-global switch, mirroring ``lsm/read_path.py``'s kernel-mode
 pattern.  See ``docs/observability.md`` for the taxonomy and schema.
 
-The calibration pass (:mod:`repro.obs.calibrate`) is deliberately NOT
-re-exported here: it needs numpy + the analytic cost model, and keeping
-it a leaf submodule keeps ``import repro.obs`` dependency-free.
+The calibration pass (:mod:`repro.obs.calibrate`) and the JAX side of the
+plane (:mod:`repro.obs.jax_bridge`: the profiler bridge and the compile
+tally) are deliberately NOT re-exported here: they need numpy or jax, and
+keeping them leaf submodules keeps ``import repro.obs`` dependency-free.
 """
 
 from .core import (NULL_SPAN, Span, Telemetry, VALID_CLOCKS, clear,
                    configure, count, disable, enabled, event,
                    events_snapshot, gauge, get, metrics_snapshot, scoped,
-                   span, track)
+                   set_annotator, span, track)
 from .trace import chrome_trace, write_trace
 
 __all__ = [
     "NULL_SPAN", "Span", "Telemetry", "VALID_CLOCKS",
     "chrome_trace", "clear", "configure", "count", "disable", "enabled",
     "event", "events_snapshot", "gauge", "get", "metrics_snapshot",
-    "scoped", "span", "track", "write_trace",
+    "scoped", "set_annotator", "span", "track", "write_trace",
 ]

@@ -25,6 +25,15 @@ Vocabulary (see ``docs/observability.md`` for the span/event taxonomy):
   inherited by every span/event inside it; the Perfetto export maps one
   track per shard/tenant/deployment.
 
+Profiler bridge: :func:`set_annotator` installs a span factory
+(``factory(name, **attrs)`` returning a context manager).  While one is
+installed every :func:`span` also enters ``factory(name, **attrs)`` with
+the attributes known at its start, whether the ring is on or off, so the
+span lands in a profiler trace on the device's clock; ``sp.set(...)``
+still feeds only the ring.  The JAX layer installs
+``jax.profiler.TraceAnnotation`` (:mod:`repro.obs.jax_bridge`, imported by
+``repro.core``); jax-free processes never install one.
+
 Determinism: with ``clock="ticks"`` timestamps are a process-global
 monotonic counter instead of wall time, so a seeded run emits a
 bit-reproducible event stream (the golden schema tests pin this).  The
@@ -56,15 +65,17 @@ DEFAULT_CAPACITY = 65536
 class Span:
     """One open span; emitted to the ring when the ``with`` block exits."""
 
-    __slots__ = ("_t", "name", "attrs", "sid", "parent", "_t0")
+    __slots__ = ("_t", "name", "attrs", "sid", "parent", "_t0", "_ann")
 
-    def __init__(self, telemetry: "Telemetry", name: str, attrs: dict):
+    def __init__(self, telemetry: "Telemetry", name: str, attrs: dict,
+                 annotation=None):
         self._t = telemetry
         self.name = name
         self.attrs = attrs
         self.sid = 0
         self.parent = 0
         self._t0 = 0.0
+        self._ann = annotation
 
     def set(self, **attrs) -> "Span":
         """Attach/overwrite attributes (op counts, IOStats deltas) before
@@ -81,15 +92,20 @@ class Span:
         stack = t.span_stack()
         self.parent = stack[-1].sid if stack else 0
         stack.append(self)
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = t.now()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         t = self._t
+        t1 = t.now()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         stack = t.span_stack()
         if stack and stack[-1] is self:
             stack.pop()
-        t.emit("span", self.name, self._t0, t.now() - self._t0, self.attrs,
+        t.emit("span", self.name, self._t0, t1 - self._t0, self.attrs,
                sid=self.sid, parent=self.parent)
 
 
@@ -112,6 +128,22 @@ class _NullSpan:
 
 
 NULL_SPAN = _NullSpan()
+
+
+class _Annotated(_NullSpan):
+    """The ring off, a profiler bridge on: the annotation alone."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, annotation):
+        self._ann = annotation
+
+    def __enter__(self) -> "_Annotated":
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self._ann.__exit__(exc_type, exc, tb)
 
 
 class Telemetry:
@@ -276,6 +308,9 @@ def _json_default_pass(x):
 
 _T: Optional[Telemetry] = None
 
+#: the profiler bridge's span factory (None: no bridge)
+_ANNOTATOR = None
+
 
 def configure(enabled: bool = True, capacity: int = DEFAULT_CAPACITY,
               clock: str = "wall", jsonl_path: str = ""
@@ -319,13 +354,21 @@ def scoped(enabled: bool = True, **kw):
         _T = prev
 
 
+def set_annotator(factory):
+    """Install the profiler bridge's span factory (None removes it);
+    returns the one it replaces."""
+    global _ANNOTATOR
+    prev, _ANNOTATOR = _ANNOTATOR, factory
+    return prev
+
+
 # -- the instrumentation surface (all no-ops when disabled) -----------------
 
 def span(name: str, **attrs):
-    t = _T
+    t, a = _T, _ANNOTATOR
     if t is None:
-        return NULL_SPAN
-    return Span(t, name, attrs)
+        return NULL_SPAN if a is None else _Annotated(a(name, **attrs))
+    return Span(t, name, attrs, None if a is None else a(name, **attrs))
 
 
 def count(name: str, n: float = 1) -> None:
