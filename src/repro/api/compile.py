@@ -187,11 +187,12 @@ _ARM_SCORERS: Dict[tuple, object] = {}
 
 
 def _arm_scorer(sys, policy: str, params: Pairs):
-    """Cached jit: phi -> (effective cost vector, exact objective at rho).
+    """Cached jit over a batch of cells: (phi, w, rho), each with a leading
+    cell axis -> (effective cost vectors (n, 4), exact objectives (n,)).
 
     ``rho`` is traced (0.0 degenerates to the nominal expected cost inside
-    ``robust_cost``), so one compile per (sys, policy, params) covers every
-    cell of the grid."""
+    ``robust_cost``), so one compile per (sys, policy, params) and batch
+    size covers every cell of the grid in one call."""
     key = (sys, policy, params)
     fn = _ARM_SCORERS.get(key)
     if fn is None:
@@ -199,13 +200,12 @@ def _arm_scorer(sys, policy: str, params: Pairs):
         from repro.core import cost_vector, policy_effective_phi
         from repro.core.robust import robust_cost
 
-        @jax.jit
         def _score_arm(phi, w, rho):
             eff = policy_effective_phi(phi, sys, policy, params)
             c = cost_vector(eff, sys)
             return c, robust_cost(c, w, rho)
 
-        fn = _ARM_SCORERS[key] = _score_arm
+        fn = _ARM_SCORERS[key] = jax.jit(jax.vmap(_score_arm))
     return fn
 
 
@@ -289,75 +289,77 @@ class CompiledExperiment:
         return TuningResult(phi=phi, cost=float("nan"),
                             design=self.primary_design, solver="fixed")
 
+    def _score(self, scorer, results: List[object], policy: str):
+        """Score one arm's tunings of every cell in one scorer call, synced
+        once (under a ``tune.score`` span): the effective cost vectors as
+        float64 rows and the exact objectives as floats, in cell order."""
+        import jax
+        from repro.core import Phi
+        phi = Phi(T=np.array([r.phi.T for r in results], np.float32),
+                  mfilt_bits=np.array([r.phi.mfilt_bits for r in results],
+                                      np.float32),
+                  K=np.stack([np.asarray(r.phi.K, np.float32)
+                              for r in results]))
+        w = np.asarray(self.W[[i for i, _ in self.cells]], np.float32)
+        rho = np.array([r or 0.0 for _, r in self.cells], np.float32)
+        with obs.span("tune.score", policy=policy, cells=len(results)):
+            C, cost = jax.device_get(scorer(phi, w, rho))
+        return np.asarray(C, np.float64), cost.tolist()
+
     def select_arms(self, solved: Dict[object, Dict[Cell, object]]) -> Report:
-        """Joint policy-arm selection + the model-side report skeleton
-        (each scorer call, up to its sync, under a ``tune.score`` span).
+        """Joint policy-arm selection + the model-side report skeleton.
 
         ``solved`` maps design -> cell -> TuningResult (the backends'
         output).  Each arm is scored by the exact objective of its
         *effective* phi — expected cost for nominal cells, the cold-grid
         KL-dual worst case at the cell's rho for robust cells — through one
-        cached jit per (policy, params); ties break to the first arm in
-        spec order (the primary arm), so single-arm specs carry the
-        TuningResult through untouched."""
+        cached jit per (policy, params), called once per policy over all
+        cells; ties break to the first arm in spec order (the primary arm),
+        so single-arm specs carry the TuningResult through untouched."""
         spec = self.spec
+        policies = spec.design.policies
         fixed = self._fixed_phi() if spec.design.fixed is not None else None
         scorers = {pol: _arm_scorer(self.sys, pol,
                                     spec.design.params_for(pol))
-                   for pol in spec.design.policies}
+                   for pol in policies}
+        results: Dict[str, List[object]] = {}
+        models: Dict[str, np.ndarray] = {}
+        costs: Dict[str, List[float]] = {}
+        for pol in policies:
+            results[pol] = [fixed] * len(self.cells) if fixed is not None \
+                else [solved[(self.arm_design[pol], spec.design.n_starts)][c]
+                      for c in self.cells]
+            models[pol], costs[pol] = self._score(scorers[pol], results[pol],
+                                                  pol)
         tunings: Dict[Cell, Dict[str, object]] = {}
         arm_costs: Dict[Cell, Dict[str, float]] = {}
         chosen: Dict[Cell, str] = {}
         model_costs: Dict[Cell, Dict[str, np.ndarray]] = {}
+        for j, cell in enumerate(self.cells):
+            tunings[cell] = {pol: results[pol][j] for pol in policies}
+            arm_costs[cell] = cell_costs = {pol: costs[pol][j]
+                                            for pol in policies}
+            chosen[cell] = min(policies, key=lambda p: (cell_costs[p],
+                                                        policies.index(p)))
+            model_costs[cell] = {pol: models[pol][j] for pol in policies}
+        B = None if self.bench is None else np.asarray(self.bench, np.float64)
         bench_costs: Dict[Cell, np.ndarray] = {}
-        for cell in self.cells:
-            i, rho = cell
-            w = np.asarray(self.W[i], np.float32)
-            arms: Dict[str, object] = {}
-            costs: Dict[str, float] = {}
-            models: Dict[str, np.ndarray] = {}
-            for pol in spec.design.policies:
-                r = fixed if fixed is not None \
-                    else solved[(self.arm_design[pol],
-                                 spec.design.n_starts)][cell]
-                with obs.span("tune.score", policy=pol):
-                    c, cost = scorers[pol](r.phi, w,
-                                           np.float32(rho or 0.0))
-                    costs[pol] = float(cost)
-                arms[pol] = r
-                models[pol] = np.asarray(c, np.float64)
-            best = min(costs, key=lambda p: (costs[p],
-                                             spec.design.policies.index(p)))
-            tunings[cell] = arms
-            arm_costs[cell] = costs
-            chosen[cell] = best
-            model_costs[cell] = models
-            if self.bench is not None:
-                bench_costs[cell] = np.asarray(self.bench, np.float64) \
-                    @ models[best]
+        if B is not None:
+            best = np.stack([model_costs[c][chosen[c]] for c in self.cells])
+            bench_costs = dict(zip(self.cells, best @ B.T))
         # -- the design-space axis: per-arm tunings + benchmark costs ------
         # (scored through the primary policy's effective-phi scorer, so a
         # space arm equals a separate spec with that primary space exactly)
         design_tunings: Dict[str, Dict[Cell, object]] = {}
         design_bench_costs: Dict[str, Dict[Cell, np.ndarray]] = {}
-        primary = spec.design.policies[0]
-        primary_scorer = scorers[primary]
+        primary = policies[0]
         for name, key in self.space_arms:
             per_cell = dict(solved[key])
             design_tunings[name] = per_cell
-            if self.bench is not None:
-                B = np.asarray(self.bench, np.float64)
-                costs_d: Dict[Cell, np.ndarray] = {}
-                for cell in self.cells:
-                    i, rho = cell
-                    with obs.span("tune.score", policy=primary):
-                        c, _ = primary_scorer(
-                            per_cell[cell].phi,
-                            np.asarray(self.W[i], np.float32),
-                            np.float32(rho or 0.0))
-                        c = np.asarray(c, np.float64)
-                    costs_d[cell] = B @ c
-                design_bench_costs[name] = costs_d
+            if B is not None:
+                C, _ = self._score(scorers[primary],
+                                   [per_cell[c] for c in self.cells], primary)
+                design_bench_costs[name] = dict(zip(self.cells, C @ B.T))
         return Report(spec=spec, sys=self.sys, cells=list(self.cells),
                       tunings=tunings, arm_costs=arm_costs, chosen=chosen,
                       model_costs=model_costs, bench_costs=bench_costs,

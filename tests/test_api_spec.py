@@ -294,3 +294,106 @@ def test_fixed_design_skips_tuning():
     # the lazy arm's effective profile differs -> different model cost
     mc = report.model_costs[(0, None)]
     assert not np.allclose(mc["klsm"], mc["lazy_leveling"])
+
+
+# ---------------------------------------------------------------------------
+# Batched arm scoring against a per-cell reference
+# ---------------------------------------------------------------------------
+
+ARM_POLICIES = ("klsm", "lazy_leveling")
+ARM_PARAMS = (("lazy_leveling", (("fill", 0.3),)),)
+
+
+@pytest.fixture(scope="module", params=["tuned", "fixed"])
+def arm_case(request):
+    """A two-policy spec (nominal + two radii, a benchmark set, and for the
+    tuned design one design-space arm), lowered and solved inline."""
+    from repro.api import compile_spec, get_backend
+    if request.param == "fixed":
+        design = DesignSpec(policies=ARM_POLICIES, policy_params=ARM_PARAMS,
+                            fixed=(6.0, 4.0, 1.0))
+    else:
+        design = DesignSpec(policies=ARM_POLICIES, policy_params=ARM_PARAMS,
+                            spaces=(("lazy_leveling", 4),), **SMALL)
+    spec = _spec(workload=WorkloadSpec(indices=WIDX, rhos=RHOS, nominal=True,
+                                       bench_n=50),
+                 design=design)
+    cx = compile_spec(spec)
+    backend = get_backend("inline")
+    solved = {key: backend.solve(plan)
+              for key, plan in cx.tuning_plans().items()}
+    return cx, solved
+
+
+def _per_cell_scorer(sys, policy, params):
+    import jax
+    from repro.core import cost_vector, policy_effective_phi
+    from repro.core.robust import robust_cost
+
+    def score(phi, w, rho):
+        c = cost_vector(policy_effective_phi(phi, sys, policy, params), sys)
+        return c, robust_cost(c, w, rho)
+
+    return jax.jit(score)
+
+
+def test_batched_arm_selection_matches_per_cell(arm_case):
+    """One scorer call per policy gives what one call per cell gives."""
+    cx, solved = arm_case
+    d = cx.spec.design
+    report = cx.select_arms(solved)
+    scorers = {p: _per_cell_scorer(cx.sys, p, d.params_for(p))
+               for p in d.policies}
+    fixed = cx._fixed_phi() if d.fixed is not None else None
+    B = np.asarray(cx.bench, np.float64)
+
+    def ref(pol, r, cell):
+        i, rho = cell
+        c, cost = scorers[pol](r.phi, np.asarray(cx.W[i], np.float32),
+                               np.float32(rho or 0.0))
+        return np.asarray(c, np.float64), float(cost)
+
+    for cell in cx.cells:
+        want = {}
+        for pol in d.policies:
+            r = report.tunings[cell][pol]
+            if fixed is None:
+                assert r is solved[(cx.arm_design[pol], d.n_starts)][cell]
+            else:
+                assert r.solver == "fixed" and float(r.phi.T) == 6.0
+            want[pol] = ref(pol, r, cell)
+            got = report.arm_costs[cell][pol]
+            assert type(got) is float
+            np.testing.assert_allclose(got, want[pol][1], rtol=1e-5)
+            model = report.model_costs[cell][pol]
+            assert model.dtype == np.float64 and model.shape == (4,)
+            np.testing.assert_allclose(model, want[pol][0], rtol=1e-5)
+        a, b = (want[p][1] for p in d.policies)
+        if abs(a - b) > 1e-5 * max(abs(a), abs(b)):
+            assert report.chosen[cell] == d.policies[int(b < a)]
+        np.testing.assert_allclose(report.bench_costs[cell],
+                                   B @ want[report.chosen[cell]][0],
+                                   rtol=1e-5)
+    assert set(report.design_bench_costs) == {n for n, _ in cx.space_arms}
+    for name, key in cx.space_arms:
+        for cell in cx.cells:
+            r = report.design_tunings[name][cell]
+            assert r is solved[key][cell]
+            np.testing.assert_allclose(report.design_bench_costs[name][cell],
+                                       B @ ref(d.policies[0], r, cell)[0],
+                                       rtol=1e-5)
+
+
+def test_arm_selection_scores_each_arm_in_one_call(arm_case):
+    """One ``tune.score`` span per policy and per space arm, each over
+    every cell."""
+    from repro import obs
+    cx, solved = arm_case
+    d = cx.spec.design
+    with obs.scoped(enabled=True, clock="ticks"):
+        cx.select_arms(solved)
+        spans = [e for e in obs.events_snapshot()
+                 if e["kind"] == "span" and e["name"] == "tune.score"]
+    assert [s["attrs"]["policy"] for s in spans] == \
+        list(d.policies) + [d.policies[0]] * len(cx.space_arms)
+    assert all(s["attrs"]["cells"] == len(cx.cells) for s in spans)
