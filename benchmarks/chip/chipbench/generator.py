@@ -13,6 +13,11 @@ A traffic file (``traffic/<name>.json``) names its kind under
 * ``checks()`` compares what the window produced with the plain reference
   and returns ``(name, value, limit)`` triples; ``value <= limit`` passes.
 
+Each kind also carries its test-time hooks (``small``, ``faults``,
+``cpu_control``; the whole contract is in ``chipbench/kinds``).
+:func:`kind` finds a kind by name: the three below, or one of
+``chipbench/kinds/<name>.py``.
+
 Kinds:
 
 * ``experiment``: a tuning experiment through ``api.run_experiment`` per
@@ -27,13 +32,16 @@ Kinds:
 from __future__ import annotations
 
 import dataclasses
+import importlib
+import re
 from typing import Dict, List, Tuple
 
 import numpy as np
 
 from .reference import cost_model as ref
 from .reference.kv_model import KVModel
-from . import ycsb
+from . import faults as planted
+from . import kinds, ycsb
 
 Check = Tuple[str, float, float]
 
@@ -64,6 +72,14 @@ class TuneBase:
 
     #: the traced run starts at the window (the window drives the device)
     trace_setup = False
+    #: its faults patch code that runs traced: a test clears JAX's caches
+    #: around each run, so that no program compiled with a fault survives
+    fresh_jit = True
+
+    @staticmethod
+    def faults(traffic: dict) -> dict:
+        """The faults that can fire in a tuning cell."""
+        return dict(planted.TUNE)
 
     def __init__(self, config: dict, traffic: dict, seed: int):
         self.config = config
@@ -168,6 +184,14 @@ def _mean(gaps: List[np.ndarray]) -> float:
 class TuneExperiment(TuneBase):
     """``api.run_experiment`` over a (workload x rho) grid per request."""
 
+    cpu_control = True
+
+    @staticmethod
+    def small(config: dict, traffic: dict) -> None:
+        traffic.update(workloads=traffic["workloads"][:5],
+                       rhos=traffic["rhos"][:2], bench_n=100)
+        config["tuner"].update(n_starts=16, steps=100)
+
     def setup(self) -> None:
         from repro.api import DesignSpec, ExperimentSpec, WorkloadSpec
         t, tuner = self.traffic, self.config["tuner"]
@@ -238,6 +262,13 @@ def algorithm1_rho(counts: np.ndarray) -> float:
 class RetuneStorm(TuneBase):
     """``online.retune_fleet`` over a fleet's fired triggers per request."""
 
+    cpu_control = False
+
+    @staticmethod
+    def small(config: dict, traffic: dict) -> None:
+        traffic.update(tenants=5, pool=2)
+        config["retune"].update(n_starts=16, steps=100)
+
     def setup(self) -> None:
         t = self.traffic
         F, nw, ops = int(t["tenants"]), int(t["windows"]), int(t["window_ops"])
@@ -293,6 +324,25 @@ class YCSB:
     #: nothing in the window runs on the device, so the traced run starts
     #: before the deployment's tuning, which does
     trace_setup = True
+    cpu_control = True
+    fresh_jit = False
+
+    @staticmethod
+    def small(config: dict, traffic: dict) -> None:
+        n = 20_000
+        config["engine"]["n_records"] = n
+        config["system"].update(N=float(n), min_buf_bits=64.0 * 8192)
+        config["deployment"].update(n_starts=8, steps=40)
+        traffic.update(pool_requests=64, readback=500, request_ops=200)
+
+    @staticmethod
+    def faults(traffic: dict) -> dict:
+        """The faults that can fire in a YCSB cell: a mix without updates
+        stores nothing that could be dropped."""
+        out = dict(planted.SERVE)
+        if not float(traffic["update"]):
+            del out["writes_dropped"]
+        return out
 
     def __init__(self, config: dict, traffic: dict, seed: int):
         self.config = config
@@ -416,3 +466,24 @@ class YCSB:
 
 
 KINDS = {"experiment": TuneExperiment, "storm": RetuneStorm, "ycsb": YCSB}
+
+KIND_NAME = re.compile(r"^[a-z0-9_]+$")
+
+
+def kind(name: str):
+    """The traffic kind ``name``: ``KINDS[name]``, else the ``KIND`` of the
+    module ``chipbench/kinds/<name>.py`` (found on ``kinds.__path__``)."""
+    if name in KINDS:
+        return KINDS[name]
+    where = (f"not in chipbench.generator.KINDS {sorted(KINDS)} and no "
+             f"{name}.py in chipbench/kinds ({list(kinds.__path__)})")
+    if not KIND_NAME.match(name):
+        raise KeyError(f"traffic kind {name!r}: not a kind name "
+                       f"({KIND_NAME.pattern}); {where}")
+    module = f"{kinds.__name__}.{name}"
+    try:
+        return importlib.import_module(module).KIND
+    except ModuleNotFoundError as e:
+        if e.name != module:
+            raise
+        raise KeyError(f"no traffic kind {name!r}: {where}") from None

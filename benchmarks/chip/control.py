@@ -14,10 +14,10 @@ record and the acknowledged updates of every second request.  Prints one
 JSON line per seed and, last, the largest sound and the smallest control
 reading of each number.
 
-With ``--fault <name>`` one of ``chipbench.faults`` is planted in the
-program first, and only the program's readings are taken: each seed's
-line holds the fault's reading of each number, and the last line the
-smallest.
+With ``--fault <name>`` one of the faults of the cell's kind (its
+``faults(traffic)``) is planted in the program first, and only the
+program's readings are taken: each seed's line holds the fault's reading
+of each number, and the last line the smallest.
 
 The limits in the traffic files are set from these readings; the
 benchmark's own runs do not run the control.
@@ -39,7 +39,7 @@ for _p in (HERE, os.path.join(ROOT, "src")):
         sys.path.insert(0, _p)
 
 import run as bench  # noqa: E402
-from chipbench import faults  # noqa: E402
+from chipbench import generator  # noqa: E402
 
 
 def readings(spec: dict, seed: int, seconds: float, need_chip: bool = True,
@@ -51,11 +51,10 @@ def readings(spec: dict, seed: int, seconds: float, need_chip: bool = True,
         enable_compile_cache()
         bench.require_chips(int(spec["cell"]["chips"]))
     import ml_dtypes
-    from chipbench import generator
-    gen = generator.KINDS[spec["traffic"]["generator"]](
+    gen = generator.kind(spec["traffic"]["generator"])(
         spec["config"], spec["traffic"], seed)
     gen.setup()
-    if isinstance(gen, generator.YCSB):
+    if hasattr(gen, "control_model"):
         gen.control_model()
     bench.run_window(gen, seconds, lambda _: contextlib.nullcontext())
     sound = gen.checks()
@@ -70,13 +69,17 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
-    ap.add_argument("--fault", choices=sorted({**faults.TUNE,
-                                               **faults.SERVE}))
+    ap.add_argument("--fault", help="one of the faults the cell's kind "
+                    "names (its ``faults(traffic)``)")
     args = ap.parse_args(argv)
     spec = bench.load_cell(args.workload)
     if args.fault:
-        {**faults.TUNE, **faults.SERVE}[args.fault](
-            types.SimpleNamespace(setattr=setattr))
+        kind = generator.kind(spec["traffic"]["generator"])
+        planted = kind.faults(spec["traffic"])
+        if args.fault not in planted:
+            ap.error(f"--fault {args.fault}: {args.workload} can have "
+                     f"{sorted(planted)}")
+        planted[args.fault](types.SimpleNamespace(setattr=setattr))
     try:
         rows = []
         for seed in args.seeds:

@@ -41,6 +41,8 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 #: where a traced run writes its profile (inside the checkout, emptied first)
 TRACE_DIR = os.path.join(ROOT, ".chipbench", "trace")
+#: the traffic files' directory, relative to the checkout
+TRAFFIC = os.path.relpath(os.path.join(HERE, "traffic"), ROOT)
 
 #: the benchmark's own profiler spans
 SPANS = {"window": "bench.window", "setup": "bench.setup",
@@ -63,19 +65,19 @@ def load_json(path: str) -> dict:
         return json.load(f)
 
 
-def load_cell(name: str) -> dict:
-    """The cell ``name`` of ``BENCHMARK.json``, with its configuration,
-    traffic and metric entries resolved."""
-    bench = load_json(os.path.join(ROOT, "BENCHMARK.json"))
+def load_cell(name: str, root: str = ROOT) -> dict:
+    """The cell ``name`` of ``<root>/BENCHMARK.json`` (the checkout's by
+    default), with its configuration, traffic and metric entries resolved;
+    every path is taken under ``root``."""
+    bench = load_json(os.path.join(root, "BENCHMARK.json"))
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
         raise KeyError(f"no workload {name!r} in BENCHMARK.json; "
                        f"known: {sorted(cells)}")
     cell = cells[name]
     configs = {c["name"]: c for c in bench["configs"]}
-    config = load_json(os.path.join(ROOT, configs[cell["config"]]["file"]))
-    traffic = load_json(os.path.join(HERE, "traffic",
-                                     cell["traffic"] + ".json"))
+    config = load_json(os.path.join(root, configs[cell["config"]]["file"]))
+    traffic = load_json(os.path.join(root, TRAFFIC, cell["traffic"] + ".json"))
     e2e = [m for m in bench["end_to_end"]
            if name in m.get("workloads", [name])]
     names = {m["name"] for m in e2e}
@@ -175,7 +177,7 @@ def run(spec: dict, seed: int, seconds: float, trace: bool,
             compiles[0] += 1
 
     jax.monitoring.register_event_duration_secs_listener(on_event)
-    gen = generator.KINDS[spec["traffic"]["generator"]](
+    gen = generator.kind(spec["traffic"]["generator"])(
         spec["config"], spec["traffic"], seed)
 
     def annotate(label: str):

@@ -1,5 +1,7 @@
 """Cells of BENCHMARK.json cut to a size a CPU test run holds, with the
-cells' own traffic kinds, checks and limits."""
+cells' own traffic kinds, checks and limits.  Each kind cuts its own cells
+(its ``small``); the per-cell tests take their cases from BENCHMARK.json
+through :func:`cells`, :func:`fault_cases` and :func:`control_cells`."""
 
 import os
 import sys
@@ -11,26 +13,41 @@ for _p in (CHIP, os.path.join(ROOT, "src")):
         sys.path.insert(0, _p)
 
 import run as runner  # noqa: E402
+from chipbench import generator  # noqa: E402
 
 SEED = 2 ** 33 + 7
 
 
-def small(name: str) -> dict:
-    spec = runner.load_cell(name)
-    t, c = spec["traffic"], spec["config"]
-    if t["generator"] == "experiment":
-        t.update(workloads=t["workloads"][:5], rhos=t["rhos"][:2],
-                 bench_n=100)
-        c["tuner"].update(n_starts=16, steps=100)
-    elif t["generator"] == "storm":
-        t.update(tenants=5, pool=2)
-        c["retune"].update(n_starts=16, steps=100)
-    else:
-        n = 20_000
-        c["engine"]["n_records"] = n
-        c["system"].update(N=float(n), min_buf_bits=64.0 * 8192)
-        c["deployment"].update(n_starts=8, steps=40)
-        t.update(pool_requests=64, readback=500, request_ops=200)
+def kind_of(spec: dict):
+    """The traffic kind of a cell (as ``runner.load_cell`` gives it)."""
+    return generator.kind(spec["traffic"]["generator"])
+
+
+def cells(root: str = ROOT) -> list:
+    """The names of ``<root>/BENCHMARK.json``'s cells."""
+    return [w["name"] for w in
+            runner.load_json(os.path.join(root, "BENCHMARK.json"))
+            ["workloads"]]
+
+
+def fault_cases(root: str = ROOT) -> list:
+    """``(cell, fault)`` for every fault each cell's kind names for it."""
+    out = []
+    for name in cells(root):
+        spec = runner.load_cell(name, root)
+        out += [(name, f) for f in kind_of(spec).faults(spec["traffic"])]
+    return out
+
+
+def control_cells(root: str = ROOT) -> list:
+    """The cells whose kind has its control run by the CPU test."""
+    return [name for name in cells(root)
+            if kind_of(runner.load_cell(name, root)).cpu_control]
+
+
+def small(name: str, root: str = ROOT) -> dict:
+    spec = runner.load_cell(name, root)
+    kind_of(spec).small(spec["config"], spec["traffic"])
     return spec
 
 

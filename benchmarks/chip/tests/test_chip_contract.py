@@ -6,6 +6,8 @@ import importlib.util
 import json
 import os
 import re
+import shutil
+import time
 
 import pytest
 
@@ -147,17 +149,41 @@ def test_traffic_files_name_a_generator_and_limits(bench):
     for w in bench["workloads"]:
         with open(os.path.join(CHIP, "traffic", w["traffic"] + ".json")) as f:
             t = json.load(f)
-        kind = generator.KINDS[t["generator"]]
+        kind = generator.kind(t["generator"])
         if issubclass(kind, generator.TuneBase):
             for n in ("score_gap", "opt_gap_mean"):
                 assert isinstance(t["limits"][n], float), (w["name"], n)
 
 
-def test_every_reader_reads_a_run(bench):
+def _recorded(tmp_path, name):
+    """A directory holding a copy of the recorded trace that ``name``
+    reads, as a traced run leaves its own: ``data/<name>.xplane.pb`` where
+    the metric brings its own, else ``data/progspans.xplane.pb``, which
+    holds the program's spans."""
+    data = os.path.join(CHIP, "tests", "data")
+    own = os.path.join(data, name + ".xplane.pb")
+    d = tmp_path / name
+    if not d.exists():
+        d.mkdir()
+        shutil.copy(own if os.path.exists(own) else
+                    os.path.join(data, "progspans.xplane.pb"),
+                    d / "run.xplane.pb")
+    return str(d)
+
+
+def test_every_reader_reads_a_run(bench, tmp_path, monkeypatch):
     """Every metric's reader turns a run's records, counters and trace
-    reduction into a finite positive number (or, without a trace, nothing
-    for a trace metric)."""
+    into a finite positive number.  Without a trace, a reader of the
+    device trace gives nothing, and any other gives nothing or the same
+    number (what it reads does not come from the trace), whether or not a
+    trace file lies where a traced run leaves one.  The program's spans
+    come from a recorded trace, and its compile tally holds a compile made
+    here before the first request."""
+    import jax
+    import jax.numpy as jnp
+    import repro.core  # noqa: F401  (installs the compile tally)
     import run as runner
+    from chipbench import progspans
     from chipbench.xplane import Reduction
     from repro.lsm import IOStats
     io = IOStats(random_reads=1200, comp_pages_read=300,
@@ -165,21 +191,40 @@ def test_every_reader_reads_a_run(bench):
                                                   "q": 0, "w": 500})
     counters = {"io": io, "reads": 1000, "writes": 500, "f_a": 1.0,
                 "f_seq": 1.0, "select_s": [0.25, 0.27]}
-    records = [(0.0, 0.5, 90, True), (0.5, 1.1, 90, True)]
+    # a program with a constant of its own: no cache can serve its compile
+    jax.jit(lambda x: jnp.sin(x) * time.time())(jnp.arange(3.0)
+                                                 ).block_until_ready()
+    t = time.perf_counter()
+    records = [(t, t + 0.5, 90, True), (t + 0.5, t + 1.1, 90, True)]
     trace = Reduction(window_s=1.0, busy_s=0.2, devices=1,
                       modules={"jit__solve_many": (4, 0.1)}, idle_gaps=[],
                       spans={})
+    empty = tmp_path / "no-trace"
+    empty.mkdir()
     for w in bench["workloads"]:
         spec = runner.load_cell(w["name"])
         for traced, group in ((None, "end_to_end"), (trace, "per_layer")):
-            ctx = runner.Context(spec, 12.5, records, counters, traced)
             for m in spec[group]:
-                v = runner.metric_reader(m["name"])(ctx)
-                assert v is not None and 0 < v < float("inf"), m["name"]
-        ctx = runner.Context(spec, 12.5, records, counters, None)
-        for m in spec["per_layer"]:
-            if m["source"] == "device_trace":
-                assert runner.metric_reader(m["name"])(ctx) is None
+                monkeypatch.setattr(progspans, "TRACE_DIR",
+                                    _recorded(tmp_path, m["name"]))
+                read = runner.metric_reader(m["name"])
+                v = read(runner.Context(spec, 12.5, records, counters,
+                                        traced))
+                assert v is not None and 0 < v < float("inf"), \
+                    (w["name"], m["name"], v)
+                if traced is None:
+                    continue
+                # no trace in the run: a stale file where traces go must
+                # not be read, so the reading is the same without one
+                bare = read(runner.Context(spec, 12.5, records, counters,
+                                           None))
+                monkeypatch.setattr(progspans, "TRACE_DIR", str(empty))
+                none = read(runner.Context(spec, 12.5, records, counters,
+                                           None))
+                assert bare == none, (w["name"], m["name"], bare, none)
+                assert bare is None or (m["source"] != "device_trace"
+                                        and bare == v), \
+                    (w["name"], m["name"], bare)
 
 
 def test_peaks_table():

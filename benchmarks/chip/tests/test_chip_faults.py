@@ -1,53 +1,39 @@
 """A run drives the timed path with a fault planted underneath and sees
 ``correct`` come out false, once for each fault the cell can have; the
-same run without a fault is correct.  The cells are cut to a CPU test's
-size (``chip_small``); the checks and limits are the cells' own."""
+same run without a fault is correct.  The cases come from BENCHMARK.json:
+every cell, and each fault its kind names for it (``faults(traffic)``).
+The cells are cut to a CPU test's size (``chip_small``); the checks and
+limits are the cells' own."""
 
 import jax
 import pytest
 
-from chip_small import run_small
+from chip_small import cells, fault_cases, kind_of, run_small
 
-from chipbench import faults
-
-TUNE = ("tune.fig6", "tune.storm-64")
-SERVE = ("serve.ycsb-a", "serve.ycsb-c")
+import run as runner
 
 
 @pytest.fixture
-def fresh_jit():
-    jax.clear_caches()
+def fresh_jit(cell):
+    """JAX's caches cleared around the run where the cell's kind plants
+    its faults in traced code."""
+    fresh = getattr(kind_of(runner.load_cell(cell)), "fresh_jit", False)
+    if fresh:
+        jax.clear_caches()
     yield
-    jax.clear_caches()
+    if fresh:
+        jax.clear_caches()
 
 
-@pytest.mark.parametrize("cell", TUNE)
-def test_tune_sound(cell, fresh_jit):
+@pytest.mark.parametrize("cell", cells())
+def test_sound(cell, fresh_jit):
     out = run_small(cell)
     assert out["correct"], out["checks"]
 
 
-@pytest.mark.parametrize("fault", list(faults.TUNE.values()))
-@pytest.mark.parametrize("cell", TUNE)
-def test_tune_fault(cell, fault, monkeypatch, fresh_jit):
-    fault(monkeypatch)
-    out = run_small(cell)
-    assert not out["correct"], out["checks"]
-
-
-@pytest.mark.parametrize("cell", SERVE)
-def test_serve_sound(cell):
-    out = run_small(cell)
-    assert out["correct"], out["checks"]
-
-
-@pytest.mark.parametrize("cell,fault", [
-    ("serve.ycsb-a", faults.writes_dropped),  # YCSB C writes nothing
-    ("serve.ycsb-a", faults.half_session),
-    ("serve.ycsb-c", faults.half_session),
-    ("serve.ycsb-a", faults.read_altered),
-    ("serve.ycsb-c", faults.read_altered)])
-def test_serve_fault(cell, fault, monkeypatch):
-    fault(monkeypatch)
+@pytest.mark.parametrize("cell,fault", fault_cases())
+def test_fault(cell, fault, monkeypatch, fresh_jit):
+    spec = runner.load_cell(cell)
+    kind_of(spec).faults(spec["traffic"])[fault](monkeypatch)
     out = run_small(cell)
     assert not out["correct"], out["checks"]

@@ -15,8 +15,10 @@ def _run(cwd, env_extra=None):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("PYTHONPATH", None)
     env.update(env_extra or {})
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        cell = json.load(f)["workloads"][0]["name"]
     return subprocess.run(
-        [sys.executable, "benchmarks/chip/run.py", "--workload", "tune.fig6",
+        [sys.executable, "benchmarks/chip/run.py", "--workload", cell,
          "--seed", str(2 ** 33 + 1), "--seconds", "1", "--trace", "0"],
         cwd=cwd, env=env, capture_output=True, text=True, timeout=300)
 

@@ -9,7 +9,7 @@ import time
 
 import pytest
 
-from chip_small import CHIP
+from chip_small import CHIP, ROOT, cells
 
 import run as runner
 from chipbench import progspans
@@ -18,10 +18,11 @@ from chipbench.xplane import Reduction
 DATA = os.path.join(CHIP, "tests", "data")
 SPAN_METRICS = ("select_score_ms", "grid_problems_per_cell", "read_batch_ms",
                 "flush_compact_ms", "populate_s")
+TALLY_METRICS = ("setup_compiles", "setup_compile_s")
 
 
-def _ctx(records=None, trace=True):
-    spec = runner.load_cell("tune.fig6")
+def _ctx(records=None, trace=True, cell=None):
+    spec = runner.load_cell(cell or cells()[0])
     reduction = Reduction(window_s=1.0, busy_s=0.2, devices=1, modules={},
                           idle_gaps=[], spans={}) if trace else None
     if records is None:
@@ -118,3 +119,24 @@ def test_setup_compiles_without_the_tally_is_absent(monkeypatch):
     ctx = _ctx([(time.perf_counter(), 0, 1, True)])
     for name in ("setup_compiles", "setup_compile_s"):
         assert runner.metric_reader(name)(ctx) is None
+
+
+@pytest.mark.parametrize("name", SPAN_METRICS + TALLY_METRICS)
+def test_registered_reader_reads_each_of_its_cells(recorded, name):
+    """The reader has its entry in BENCHMARK.json, and in each cell the
+    entry lists it reads the recorded trace, with a compile made before
+    the first request, at a finite positive number."""
+    import jax
+    import jax.numpy as jnp
+    import repro.core  # noqa: F401  (installs the compile tally)
+    bench = runner.load_json(os.path.join(ROOT, "BENCHMARK.json"))
+    entry, = [m for m in bench["per_layer"] if m["name"] == name]
+    # a program with a constant of its own: no cache can serve its compile
+    jax.jit(lambda x: jnp.cos(x) * time.time())(jnp.arange(3.0)
+                                                 ).block_until_ready()
+    t = time.perf_counter()
+    for cell in entry["workloads"]:
+        ctx = _ctx([(t, t + 0.1, 90, True)] * 3, cell=cell)
+        assert entry in runner.load_cell(cell)["per_layer"]
+        value = runner.metric_reader(name)(ctx)
+        assert value is not None and 0 < value < float("inf"), (cell, value)
